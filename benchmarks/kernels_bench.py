@@ -1,16 +1,16 @@
 """Per-kernel regression harness: parity, counted HBM traffic, roofline.
 
-Each shape emits two CSV rows — ``…/interpret`` (Pallas interpret mode, the
-kernel body running in Python; available everywhere) and ``…/compiled``
-(Mosaic-compiled Pallas on TPU; on CPU the jit'd jnp reference stands in,
-flagged ``impl=ref_jnp``). Timing in interpret mode validates plumbing, not
-speed — the performance claims are *counted*, not timed: the ``derived``
-column carries analytic bytes/FLOPs from ``repro.kernels.counting`` (replay
-of the exact trimmed grids and index-map clamps) plus the roofline terms
-from ``benchmarks.roofline.kernel_roofline``. ``--check`` turns the harness
-into a gate: parity vs the jnp oracles, trimmed grids strictly cheaper than
-their rectangular/full baselines, fused SGMV exactly one pass over the
-activation tile, zero-length paged rows exactly zero. See README.md §Kernels.
+Each shape emits an ``…/interpret`` row (Pallas interpret mode, the kernel
+body running in Python; available everywhere) and, only when a TPU is
+attached, a ``…/compiled`` row (the Mosaic-compiled kernel timed on that
+chip). Timing in interpret mode validates plumbing, not speed — off the chip
+the performance claims are *counted*, not timed: the ``derived`` column
+carries analytic bytes/FLOPs from ``repro.kernels.counting`` (replay of the
+exact trimmed grids and index-map clamps) plus the roofline terms from
+``benchmarks.roofline.kernel_roofline``. ``--check`` turns the harness into a
+gate: parity vs the jnp oracles, trimmed grids strictly cheaper than their
+rectangular/full baselines, fused SGMV exactly one pass over the activation
+tile, zero-length paged rows exactly zero. See README.md §Kernels.
 
 Usage:
     PYTHONPATH=src python -m benchmarks.kernels_bench [--quick] [--check]
@@ -38,9 +38,12 @@ from repro.kernels import (
 )
 
 from .common import CsvOut, fmt_fields
-from .roofline import kernel_roofline
+from .roofline import TARGET_DEVICE_KIND, kernel_roofline
 
 ON_TPU = jax.default_backend() == "tpu"
+# measured times are priced against the chip that ran them; counts alone
+# against the chip the kernels are written for
+DEVICE_KIND = jax.devices()[0].device_kind if ON_TPU else TARGET_DEVICE_KIND
 
 
 def _time(fn, *args, reps: int = 3, **kw) -> float:
@@ -59,7 +62,8 @@ def _err(a, b) -> float:
 def _roofline_tag(counts: dict, t_us: float | None = None) -> str:
     rl = kernel_roofline(counts["flops"], counts.get("hbm_bytes",
                                                      counts.get("x_bytes", 0)),
-                         measured_us=t_us if ON_TPU else None)
+                         measured_us=t_us if ON_TPU else None,
+                         device_kind=DEVICE_KIND)
     fields = ["bound_us:.2f", "dom=dominant", "ceiling_frac=ceiling_fraction:.3f"]
     if "achieved_fraction" in rl:
         fields.append("achieved_frac=achieved_fraction:.3f")
@@ -67,24 +71,22 @@ def _roofline_tag(counts: dict, t_us: float | None = None) -> str:
 
 
 def _emit_pair(out: CsvOut, name: str, kernel_fn, ref_fn, args, kw,
-               derived: str) -> tuple[float, float]:
-    """Emit interpret + compiled rows for one shape.
+               derived: str) -> tuple[float, float | None]:
+    """Emit the interpret row, and on a TPU the compiled row, for one shape.
 
-    Returns (parity_err, compiled_us) — the compiled timing is real Pallas
-    only on TPU (elsewhere the jnp reference stands in and floors must not
-    be pinned against it).
+    Returns (parity_err, compiled_us); ``compiled_us`` is None off the chip,
+    where no compiled kernel runs and nothing is timed under that name.
     """
     t_int = _time(kernel_fn, *args, interpret=True, **kw)
     got = kernel_fn(*args, interpret=True, **kw)
     want = ref_fn(*args)
     err = _err(got, want)
     out.emit(f"{name}/interpret", t_int, f"err={err:.2e};{derived}")
-    if ON_TPU:
-        t_cmp = _time(kernel_fn, *args, interpret=False, **kw)
-        out.emit(f"{name}/compiled", t_cmp, f"impl=pallas;{derived}")
-    else:
-        t_cmp = _time(jax.jit(ref_fn), *args)
-        out.emit(f"{name}/compiled", t_cmp, f"impl=ref_jnp;{derived}")
+    if not ON_TPU:
+        return err, None
+    t_cmp = _time(kernel_fn, *args, interpret=False, **kw)
+    out.emit(f"{name}/compiled", t_cmp,
+             f"impl=pallas;device={DEVICE_KIND};{derived}")
     return err, t_cmp
 
 
@@ -112,11 +114,12 @@ class Checks:
             self.failures.append(msg)
             print(f"CHECK FAIL: {msg}", file=sys.stderr)
 
-    def floor(self, kernel: str, counts: dict, compiled_us: float) -> None:
-        if not self.floors:
+    def floor(self, kernel: str, counts: dict,
+              compiled_us: float | None) -> None:
+        if not self.floors:  # off the chip there is no compiled time
             return
         rl = kernel_roofline(counts["flops"], counts["hbm_bytes"],
-                             measured_us=compiled_us)
+                             measured_us=compiled_us, device_kind=DEVICE_KIND)
         got = rl["achieved_fraction"]
         self.expect(got >= FLOORS[kernel],
                     f"{kernel}: achieved roofline fraction {got:.3f} below "
@@ -318,8 +321,8 @@ def main(argv: list[str] | None = None) -> int:
                         "exit nonzero on any failure")
     p.add_argument("--floors", action="store_true",
                    help="additionally pin achieved-vs-roofline floors "
-                        "(needs a TPU; no-op on CPU, where compiled timings "
-                        "are the jnp stand-in)")
+                        "(needs a TPU; no-op on CPU, where no compiled "
+                        "kernel is timed)")
     p.add_argument("--csv", default="",
                    help="also write the rows to this path")
     args = p.parse_args(argv)

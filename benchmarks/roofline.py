@@ -1,8 +1,8 @@
 """Roofline benchmark: reads the dry-run artifacts (results/dryrun/*.json)
 and emits the three roofline terms per (arch × shape × mesh) cell.
 
-Hardware constants: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link
-ICI (values fixed by the assignment).
+Hardware constants come from ``PEAKS``, keyed by ``jax.Device.device_kind``;
+a device kind missing from the table is an error, never a default.
 """
 
 from __future__ import annotations
@@ -14,25 +14,44 @@ from .common import CsvOut
 
 RESULTS = pathlib.Path(__file__).resolve().parent.parent / "results" / "dryrun"
 
-PEAK_FLOPS = 197e12  # per chip, bf16
-HBM_BW = 819e9  # per chip
-ICI_BW = 50e9  # per link
+# Published per-chip peaks. Source: Google Cloud documentation, "TPU v5e"
+# (cloud.google.com/tpu/docs/v5e): 197 TFLOP/s bf16, 819 GB/s of HBM
+# bandwidth, 1,600 Gbit/s of inter-chip interconnect per chip.
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 1600e9 / 8},
+}
+
+# The chip the kernels and the dry-run's 16x16 production meshes are built
+# for. Counted (shape-derived) roofline terms are priced against it when no
+# chip is attached; a measured time is always priced against the chip that
+# ran it.
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> dict:
+    """Published peaks of ``device_kind``; unknown devices are an error."""
+    if device_kind not in PEAKS:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}")
+    return PEAKS[device_kind]
 
 
 def kernel_roofline(flops: float, hbm_bytes: float,
-                    measured_us: float | None = None) -> dict:
+                    measured_us: float | None = None, *,
+                    device_kind: str) -> dict:
     """Single-chip roofline terms for one kernel invocation.
 
     ``flops``/``hbm_bytes`` come from ``repro.kernels.counting`` — analytic
     replay of the trimmed grids, not a profiler. ``ceiling_fraction`` is the
     best MXU utilization the counted traffic admits (t_comp / bound ≤ 1);
     ``achieved_fraction`` (when a measured TPU time is supplied) is
-    bound / measured — how close the run came to its own roofline. CI pins
-    floors on these for the kernel-regression job (TPU-only for achieved;
-    the counted ceiling is hardware-independent). See README.md §Kernels.
+    bound / measured — how close the run came to its own roofline. Both are
+    priced against ``device_kind``'s peaks: the chip that measured, or
+    ``TARGET_DEVICE_KIND`` for counts alone. See README.md §Kernels.
     """
-    t_comp = flops / PEAK_FLOPS
-    t_mem = hbm_bytes / HBM_BW
+    pk = peaks(device_kind)
+    t_comp = flops / pk["flops"]
+    t_mem = hbm_bytes / pk["hbm_bw"]
     bound = max(t_comp, t_mem, 1e-15)
     out = {
         "t_compute": t_comp,
@@ -47,18 +66,21 @@ def kernel_roofline(flops: float, hbm_bytes: float,
 
 
 def roofline_terms(rec: dict) -> dict:
+    """Roofline terms of one dry-run record, priced against the chip its
+    production mesh describes (``TARGET_DEVICE_KIND``)."""
+    pk = peaks(TARGET_DEVICE_KIND)
     chips = rec["num_devices"]
-    t_comp = rec["flops"] / (chips * PEAK_FLOPS)
+    t_comp = rec["flops"] / (chips * pk["flops"])
     # memory term: prefer the structural entry-only estimate (TPU-realistic);
     # XLA-CPU cost_analysis bytes count unfused elementwise chains a TPU
     # would fuse (recorded for reference as bytes_accessed).
     mem_bytes_dev = rec.get(
         "bytes_entry_per_device", rec["bytes_accessed"] / chips
     )
-    t_mem = mem_bytes_dev / HBM_BW
-    # collective_bytes is per-device link traffic (parsed from the
-    # partitioned HLO), so the term is bytes/dev over the per-link bw
-    t_coll = rec["collective_bytes"] / ICI_BW
+    t_mem = mem_bytes_dev / pk["hbm_bw"]
+    # collective_bytes is per-device traffic (parsed from the partitioned
+    # HLO), so the term is bytes/dev over the chip's interconnect bandwidth
+    t_coll = rec["collective_bytes"] / pk["ici_bw"]
     dom = max(
         ("compute", t_comp), ("memory", t_mem), ("collective", t_coll),
         key=lambda kv: kv[1],
@@ -74,7 +96,7 @@ def roofline_terms(rec: dict) -> dict:
         out["useful_flops_ratio"] = rec["model_flops"] / max(1.0, rec["flops"])
         # roofline fraction: useful work / (what the dominant term costs)
         out["roofline_fraction"] = (
-            rec["model_flops"] / (chips * PEAK_FLOPS)
+            rec["model_flops"] / (chips * pk["flops"])
         ) / max(1e-12, out["bound"])
     return out
 
@@ -150,5 +172,7 @@ def run(out: CsvOut) -> None:
             f"useful={terms.get('useful_flops_ratio', 0):.3f};"
             f"roofline_frac={terms.get('roofline_fraction', 0):.3f}",
         )
+
+
 if __name__ == "__main__":
     main()

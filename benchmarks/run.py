@@ -15,6 +15,8 @@ from __future__ import annotations
 import sys
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from .common import CsvOut
 
 MODULES = [
@@ -38,6 +40,7 @@ def main() -> None:
     import importlib
 
     selected = set(sys.argv[1:])
+    enable_compile_cache()
     out = CsvOut()
     print("name,us_per_call,derived")
     for name, modpath in MODULES:

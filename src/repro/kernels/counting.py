@@ -121,21 +121,19 @@ def paged_attention_counts(
     ``trimmed=False`` replays the historical full-grid fetch (every page of
     every sequence streamed, tokens masked after the fact).
     """
-    G = H // Hkv
     kv_ctr = _FetchCounter()
     flops = 0
     for b in range(B):
         live = max(_cdiv(lengths[b], page_size), 1)
-        for h in range(Hkv):
-            for p in range(pages_per_seq):
-                p_eff = min(p, live - 1) if trimmed else p
-                kv_ctr.visit((b, h, p_eff))
-                if not trimmed or p * page_size < lengths[b]:
-                    flops += 4 * G * page_size * D
-    kv_bytes = kv_ctr.fetches * page_size * D * itemsize * 2
+        for p in range(pages_per_seq):
+            p_eff = min(p, live - 1) if trimmed else p
+            kv_ctr.visit((b, p_eff))  # one page block holds every KV head
+            if not trimmed or p * page_size < lengths[b]:
+                flops += 4 * H * page_size * D
+    kv_bytes = kv_ctr.fetches * page_size * Hkv * D * itemsize * 2
     q_bytes = B * H * D * itemsize
     return {
-        "grid_steps": B * Hkv * pages_per_seq,
+        "grid_steps": B * pages_per_seq,
         "kv_block_fetches": kv_ctr.fetches,
         "kv_bytes": kv_bytes,
         "q_bytes": q_bytes,
@@ -165,7 +163,6 @@ def ragged_extend_counts(
     cache block of the padded rectangle, masking after the fetch) — what the
     jnp ``sdpa`` path pays.
     """
-    G = H // Hkv
     bq = min(block_q, S)
     bk = min(block_k, T)
     qb, kb = _cdiv(S, bq), _cdiv(T, bk)
@@ -174,26 +171,26 @@ def ragged_extend_counts(
     for b in range(B):
         live_q = max(_cdiv(true_lens[b], bq), 1)
         frontier = max(_cdiv(start[b] + true_lens[b], bk), 1)
-        for h in range(H):
-            for i in range(qb):
-                i_eff = min(i, live_q - 1) if trimmed else i
-                diag = (start[b] + i_eff * bq + bq - 1) // bk + 1
-                live_k = max(min(frontier, diag), 1)
-                for j in range(kb):
-                    j_eff = min(j, live_k - 1) if trimmed else j
-                    q_ctr.visit((b, h, i_eff))
-                    kv_ctr.visit((b, h // G, j_eff))
-                    active = (
-                        i * bq < true_lens[b]
-                        and j * bk < start[b] + true_lens[b]
-                        and j * bk <= start[b] + i * bq + bq - 1
-                    )
-                    if not trimmed or active:
-                        flops += 4 * bq * bk * D
-    kv_bytes = kv_ctr.fetches * bk * D * itemsize * 2
-    q_bytes = q_ctr.fetches * bq * D * itemsize
+        for i in range(qb):
+            i_eff = min(i, live_q - 1) if trimmed else i
+            diag = (start[b] + i_eff * bq + bq - 1) // bk + 1
+            live_k = max(min(frontier, diag), 1)
+            for j in range(kb):
+                j_eff = min(j, live_k - 1) if trimmed else j
+                # q blocks hold every query head, KV blocks every KV head
+                q_ctr.visit((b, i_eff))
+                kv_ctr.visit((b, j_eff))
+                active = (
+                    i * bq < true_lens[b]
+                    and j * bk < start[b] + true_lens[b]
+                    and j * bk <= start[b] + i * bq + bq - 1
+                )
+                if not trimmed or active:
+                    flops += 4 * H * bq * bk * D
+    kv_bytes = kv_ctr.fetches * bk * Hkv * D * itemsize * 2
+    q_bytes = q_ctr.fetches * bq * H * D * itemsize
     return {
-        "grid_steps": B * H * qb * kb,
+        "grid_steps": B * qb * kb,
         "kv_block_fetches": kv_ctr.fetches,
         "kv_bytes": kv_bytes,
         "q_bytes": q_bytes,

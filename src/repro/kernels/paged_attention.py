@@ -9,6 +9,12 @@ replacement for pointer-chasing. Softmax is computed online (flash-style
 running max / sum in VMEM scratch) across the page-grid dimension, which is
 sequential on TPU, so the accumulator carries across pages of one sequence.
 
+Each page block holds every KV head, ``(1, page_size, Hkv, D)``, and the q/o
+blocks every query head, ``(1, H, D)``: the last two block dims equal the
+array's, the shape Mosaic accepts (a head block of 1 in the second-minor
+position is refused). The kernel loops over KV heads inside, attending each
+head's ``G = H // Hkv`` query rows, so a page is fetched once per step.
+
 Length trimming: the prefetched ``lens`` clamp the page index map to each
 sequence's last live page — Pallas skips the DMA when consecutive grid steps
 map to the same block, so pages past ``ceil(lens[b]/page_size)`` cost no
@@ -37,19 +43,20 @@ NEG_INF = -1e30
 def _paged_attn_kernel(
     tables_ref,  # scalar prefetch: (B, pages_per_seq) int32
     lens_ref,  # scalar prefetch: (B,) int32
-    q_ref,  # (1, 1, G, D)
-    k_ref,  # (1, page, 1, D)
-    v_ref,  # (1, page, 1, D)
-    o_ref,  # (1, 1, G, D)
-    acc_ref,  # VMEM scratch (G, D) f32
-    m_ref,  # VMEM scratch (G, 1) f32
-    l_ref,  # VMEM scratch (G, 1) f32
+    q_ref,  # (1, H, D)
+    k_ref,  # (1, page, Hkv, D)
+    v_ref,  # (1, page, Hkv, D)
+    o_ref,  # (1, H, D)
+    acc_ref,  # VMEM scratch (Hkv, G, D) f32
+    m_ref,  # VMEM scratch (Hkv, G, 1) f32
+    l_ref,  # VMEM scratch (Hkv, G, 1) f32
     *,
     page_size: int,
     pages_per_seq: int,
 ):
     b = pl.program_id(0)
-    p = pl.program_id(2)
+    p = pl.program_id(1)
+    Hkv, G = acc_ref.shape[0], acc_ref.shape[1]
 
     @pl.when(p == 0)
     def _init():
@@ -61,38 +68,39 @@ def _paged_attn_kernel(
     # was already suppressed by the clamped index map below).
     @pl.when(p * page_size < lens_ref[b])
     def _attend():
-        q = q_ref[0, 0].astype(jnp.float32)  # (G, D)
-        k = k_ref[0, :, 0].astype(jnp.float32)  # (page, D)
-        v = v_ref[0, :, 0].astype(jnp.float32)  # (page, D)
-        D = q.shape[-1]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) / jnp.sqrt(
-            jnp.float32(D)
-        )  # (G, page)
+        D = q_ref.shape[-1]
         # mask tokens beyond the sequence length
         token_idx = p * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (1, page_size), 1
         )
         valid = token_idx < lens_ref[b]
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_ref[...]  # (G, 1)
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_cur)
-        p_ij = jnp.exp(s - m_cur)  # (G, page)
-        # zero masked probabilities explicitly: when every score in the page
-        # is NEG_INF, exp(s - m) is 1, not 0 — without this, a row whose
-        # length is 0 averages V instead of emitting zeros
-        p_ij = jnp.where(valid, p_ij, 0.0)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p_ij, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-            p_ij, v, preferred_element_type=jnp.float32
-        )
-        m_ref[...] = m_cur
+        for h in range(Hkv):
+            q = q_ref[0, h * G:(h + 1) * G, :].astype(jnp.float32)  # (G, D)
+            k = k_ref[0, :, h, :].astype(jnp.float32)  # (page, D)
+            v = v_ref[0, :, h, :].astype(jnp.float32)  # (page, D)
+            s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) / jnp.sqrt(
+                jnp.float32(D)
+            )  # (G, page)
+            s = jnp.where(valid, s, NEG_INF)
+            m_prev = m_ref[h]  # (G, 1)
+            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_cur)
+            # zero masked probabilities explicitly: when every score in the
+            # page is NEG_INF, exp(s - m) is 1, not 0 — without this, a row
+            # whose length is 0 averages V instead of emitting zeros
+            p_ij = jnp.where(valid, jnp.exp(s - m_cur), 0.0)  # (G, page)
+            l_ref[h] = l_ref[h] * alpha + jnp.sum(p_ij, axis=-1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * alpha + jnp.dot(
+                p_ij, v, preferred_element_type=jnp.float32
+            )
+            m_ref[h] = m_cur
 
     @pl.when(p == pages_per_seq - 1)
     def _finalize():
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(
-            o_ref.dtype
-        )
+        for h in range(Hkv):
+            o_ref[0, h * G:(h + 1) * G, :] = (
+                acc_ref[h] / jnp.maximum(l_ref[h], 1e-30)
+            ).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -109,16 +117,15 @@ def paged_attention(
     P, page_size, Hkv, _ = k_pages.shape
     pages_per_seq = block_tables.shape[1]
     G = H // Hkv
-    qg = q.reshape(B, Hkv, G, D)
-    grid = (B, Hkv, pages_per_seq)
+    grid = (B, pages_per_seq)
 
-    def _kv_map(b, h, p, t, l):
+    def _kv_map(b, p, t, l):
         # clamp to the last live page: steps past it revisit the same block,
         # and a revisited block is not re-fetched
         live = jnp.maximum((l[b] + page_size - 1) // page_size, 1)
-        return (t[b, jnp.minimum(p, live - 1)], 0, h, 0)
+        return (t[b, jnp.minimum(p, live - 1)], 0, 0, 0)
 
-    out = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(
             _paged_attn_kernel, page_size=page_size, pages_per_seq=pages_per_seq
         ),
@@ -126,18 +133,17 @@ def paged_attention(
             num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, 1, G, D), lambda b, h, p, t, l: (b, h, 0, 0)),
-                pl.BlockSpec((1, page_size, 1, D), _kv_map),
-                pl.BlockSpec((1, page_size, 1, D), _kv_map),
+                pl.BlockSpec((1, H, D), lambda b, p, t, l: (b, 0, 0)),
+                pl.BlockSpec((1, page_size, Hkv, D), _kv_map),
+                pl.BlockSpec((1, page_size, Hkv, D), _kv_map),
             ],
-            out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, p, t, l: (b, h, 0, 0)),
+            out_specs=pl.BlockSpec((1, H, D), lambda b, p, t, l: (b, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((G, D), jnp.float32),
-                pltpu.VMEM((G, 1), jnp.float32),
-                pltpu.VMEM((G, 1), jnp.float32),
+                pltpu.VMEM((Hkv, G, D), jnp.float32),
+                pltpu.VMEM((Hkv, G, 1), jnp.float32),
+                pltpu.VMEM((Hkv, G, 1), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
         interpret=interpret,
-    )(block_tables, lengths, qg, k_pages, v_pages)
-    return out.reshape(B, H, D)
+    )(block_tables, lengths, q, k_pages, v_pages)

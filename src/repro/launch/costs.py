@@ -1,20 +1,9 @@
-"""Version-tolerant accessors for XLA compiled-executable analyses.
-
-jaxlib < 0.4.36 returns ``cost_analysis()`` as a single dict (or a list with
-one dict per partition on some backends); jaxlib >= 0.4.36 returns
-``list[dict]`` everywhere, so the old ``(… or {}).get("flops", 0)`` idiom
-crashes with ``AttributeError: 'list' object has no attribute 'get'``.
-"""
+"""Accessor for XLA's cost analysis of a compiled executable."""
 
 from __future__ import annotations
 
 
 def cost_dict(compiled) -> dict:
-    """Normalize ``compiled.cost_analysis()`` to a plain dict across jaxlib
-    versions (None → {}, list[dict] → first partition's dict)."""
-    ca = compiled.cost_analysis()
-    if ca is None:
-        return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
+    """``compiled.cost_analysis()`` as a plain dict (``flops``,
+    ``bytes accessed``, ``transcendentals``, ...)."""
+    return dict(compiled.cost_analysis())

@@ -1,13 +1,12 @@
 import os
 
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_dryrun_cache")
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "10")
 
 """Multi-pod dry-run: lower + compile every (arch × shape) cell on the
 production meshes and record roofline inputs.
 
-The two lines above MUST stay first: jax locks the device count on first
+The lines above MUST stay first: jax locks the device count on first
 init, and the dry-run needs 512 placeholder host devices for the production
 meshes. (Smoke tests / benches import repro normally and see 1 device.)
 
@@ -41,6 +40,7 @@ from repro.distributed.sharding import (
     moment_specs,
     param_specs,
 )
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.costs import cost_dict
 from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import batch_specs_for, model_flops, model_state_specs
@@ -279,6 +279,7 @@ def main() -> None:
     ap.add_argument("--tag", default="",
                     help="suffix for the result file (optimized variants)")
     args = ap.parse_args()
+    enable_compile_cache()
     opts: dict = {}
     for kv in args.opt.split(","):
         if not kv:

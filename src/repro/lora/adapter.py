@@ -57,8 +57,10 @@ class AdapterStore:
             return self._host[adapter_id]
         lora = self.model.init_lora(key, 1)
         weights = {}
-        for t, (a, b) in lora.items():
-            bkey = jax.random.fold_in(key, hash(t) % (1 << 30))
+        for i, (t, (a, b)) in enumerate(lora.items()):
+            # fold in the target's index, not hash(t): str hashes change
+            # from process to process, and the weights must follow the key
+            bkey = jax.random.fold_in(key, i)
             bmat = jax.random.normal(bkey, b[:, 0].shape, jnp.float32) * scale
             weights[t] = (np.asarray(a[:, 0]), np.asarray(bmat, np.float32))
         aw = AdapterWeights(adapter_id, self.model.cfg.lora.rank, weights)

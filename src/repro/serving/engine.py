@@ -37,6 +37,7 @@ from __future__ import annotations
 import dataclasses
 import os
 import time
+import zlib
 from collections import deque
 from typing import Callable, Optional
 
@@ -167,6 +168,26 @@ class EngineConfig:
     trace_capacity: int = 200_000  # ring-buffer size before oldest-drop
 
 
+def kv_pool_spec(model_cfg, block_size: int) -> KVPoolSpec:
+    """The engine's per-token pool layout for ``model_cfg`` (float32)."""
+    if model_cfg.mla is not None:
+        # the pool stores the compressed latent + rope key as ONE
+        # pseudo-head per token (what _read_dense/_write_dense move),
+        # not the expanded num_kv_heads × head_dim layout
+        m = model_cfg.mla
+        kv_heads, head_dim = 1, m.kv_lora_rank + m.qk_rope_head_dim
+    else:
+        kv_heads, head_dim = model_cfg.num_kv_heads, model_cfg.resolved_head_dim
+    return KVPoolSpec(
+        num_layers=model_cfg.num_layers,
+        block_size=block_size,
+        kv_heads=kv_heads,
+        head_dim=head_dim,
+        dtype=jnp.float32,
+        use_v=model_cfg.mla is None,
+    )
+
+
 class ServingEngine:
     def __init__(self, model_cfg, config: EngineConfig, key=None):
         if config.schedule_mode not in ("mixed", "alternate"):
@@ -187,22 +208,7 @@ class ServingEngine:
         k1, k2 = jax.random.split(key)
         self.model = build_model(model_cfg, dtype=jnp.float32)
         self.params = self.model.init_params(k1)
-        if model_cfg.mla is not None:
-            # the pool stores the compressed latent + rope key as ONE
-            # pseudo-head per token (what _read_dense/_write_dense move),
-            # not the expanded num_kv_heads × head_dim layout
-            m = model_cfg.mla
-            kv_heads, head_dim = 1, m.kv_lora_rank + m.qk_rope_head_dim
-        else:
-            kv_heads, head_dim = model_cfg.num_kv_heads, model_cfg.resolved_head_dim
-        spec = KVPoolSpec(
-            num_layers=model_cfg.num_layers,
-            block_size=config.block_size,
-            kv_heads=kv_heads,
-            head_dim=head_dim,
-            dtype=jnp.float32,
-            use_v=model_cfg.mla is None,
-        )
+        spec = kv_pool_spec(model_cfg, config.block_size)
         self.kv_spec = spec
         # recurrent layouts (RWKV / RG-LRU hybrid) carry fixed-size state
         # snapshots instead of a per-token dense KV: their prefix cache is
@@ -346,7 +352,9 @@ class ServingEngine:
 
     # ----------------------------------------------------------------- LoRA
     def register_adapter(self, adapter_id: str, key=None) -> None:
-        key = key if key is not None else jax.random.PRNGKey(hash(adapter_id) % (1 << 30))
+        if key is None:
+            # crc32, not hash(): str hashes change from process to process
+            key = jax.random.PRNGKey(zlib.crc32(adapter_id.encode()) % (1 << 30))
         aw = self.adapters.register(adapter_id, key)
         self.manager.register_lora(adapter_id, aw.nbytes, now=self._now())
 

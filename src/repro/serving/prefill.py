@@ -146,6 +146,16 @@ class BatchPrefill:
         return total
 
     # ------------------------------------------------------------- execution
+    def step_fn(self, bucket: int):
+        """The jitted mixed step for ``bucket`` (built on first use): the
+        program every prefill chunk and mixed batch of that length runs."""
+        if bucket not in self._fns:
+            if bucket not in self.buckets:
+                raise ValueError(f"tokens padded to {bucket}, not a "
+                                 f"configured bucket {self.buckets}")
+            self._fns[bucket] = self._build(bucket)
+        return self._fns[bucket]
+
     def _build(self, bucket: int):
         model = self.model
 
@@ -187,11 +197,7 @@ class BatchPrefill:
         Returns (per-row last-real-token logits (B, V), merged cache).
         """
         bucket = int(tokens.shape[1])
-        if bucket not in self._fns:
-            if bucket not in self.buckets:
-                raise ValueError(f"tokens padded to {bucket}, not a "
-                                 f"configured bucket {self.buckets}")
-            self._fns[bucket] = self._build(bucket)
+        fn = self.step_fn(bucket)
         sm = row_mask if stat_mask is None else stat_mask
         sm = np.asarray(sm)
         real = int(np.asarray(true_lens)[sm].sum())
@@ -200,5 +206,5 @@ class BatchPrefill:
         self.stats.rows += nrows
         self.stats.tokens += real
         self.stats.pad_tokens += nrows * bucket - real
-        return self._fns[bucket](params, lora, cache, tokens, start,
-                                 true_lens, row_mask, adapter_ids)
+        return fn(params, lora, cache, tokens, start, true_lens, row_mask,
+                  adapter_ids)
