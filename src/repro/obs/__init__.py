@@ -3,7 +3,7 @@
 A zero-dependency (stdlib-only) observability layer for the serving stack:
 
 * :class:`Tracer` — monotonic-clock spans, instants and counter series in a
-  ring-buffered host-side event log, plus named counter/gauge registries.
+  ring-buffered host-side event log, plus a named counter registry.
   Every recorded value is a plain Python scalar: the tracer never touches a
   device array, so instrumented hot paths stay clean under the ``host-sync``
   libra-lint rule and armed tracing adds no device round trips.
@@ -23,6 +23,10 @@ A zero-dependency (stdlib-only) observability layer for the serving stack:
 
 The event vocabulary (``EV_*`` / ``TRACK_*``) is shared by the JAX engine
 and the discrete-event simulator so engine-vs-sim timelines diff cleanly.
+
+:mod:`repro.obs.phase` (imports JAX, so not re-exported here) puts the
+engine's step phases on the profiler's clock as ``TraceAnnotation`` spans,
+mirrored to ``TRACK_ENGINE`` while a tracer is armed.
 See README.md §Observability.
 """
 

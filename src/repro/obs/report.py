@@ -75,7 +75,8 @@ def audit_summary(events: List[Dict[str, Any]], top: int = 10) -> Dict[str, Any]
     nodes: Dict[Any, Dict[str, Any]] = {}
     for ev in events:
         name = ev.get("name", "")
-        if not name.startswith("cache."):
+        # audit events are instants; the engine's cache.* phases are spans
+        if ev.get("ph") != "i" or not name.startswith("cache."):
             continue
         counts[name] = counts.get(name, 0) + 1
         args = ev.get("args") or {}

@@ -112,10 +112,9 @@ class Tracer:
         self.events: deque = deque(maxlen=capacity)
         self.capacity = capacity
         self.dropped_events = 0
-        # Aggregate registries, independent of the ring buffer (never
-        # dropped): monotonically increasing counts and last-value gauges.
+        # Aggregate registry, independent of the ring buffer (never
+        # dropped): monotonically increasing counts.
         self.counts: Dict[str, int] = {}
-        self.gauges: Dict[str, float] = {}
 
     # -- recording ----------------------------------------------------------
 
@@ -145,15 +144,10 @@ class Tracer:
         """Bump an aggregate counter (registry, not the ring buffer)."""
         self.counts[name] = self.counts.get(name, 0) + n
 
-    def gauge(self, name: str, value: float) -> None:
-        """Set an aggregate gauge to its latest value."""
-        self.gauges[name] = value
-
     def clear(self) -> None:
         self.events.clear()
         self.dropped_events = 0
         self.counts.clear()
-        self.gauges.clear()
 
     # -- queries ------------------------------------------------------------
 
@@ -211,7 +205,6 @@ class Tracer:
                 "producer": "repro.obs libra-trace",
                 "droppedEvents": self.dropped_events,
                 "counts": dict(self.counts),
-                "gauges": dict(self.gauges),
             },
         }
 
@@ -237,9 +230,6 @@ class NullTracer(Tracer):
         pass
 
     def count(self, name: str, n: int = 1) -> None:
-        pass
-
-    def gauge(self, name: str, value: float) -> None:
         pass
 
 

@@ -68,6 +68,19 @@ from ..obs import (
     slot_track,
     trace_env_enabled,
 )
+from ..obs.phase import (
+    PH_ADMIT,
+    PH_DISPATCH,
+    PH_FINISH,
+    PH_GATHER,
+    PH_MERGE,
+    PH_PLAN,
+    PH_READBACK,
+    PH_SCATTER,
+    PH_SWAP,
+    PH_SWAPPER,
+    phase,
+)
 from ..kvcache import (
     KVPoolSpec,
     PagedKVPool,
@@ -418,6 +431,11 @@ class ServingEngine:
         Perfetto; see repro.obs). A disabled tracer dumps an empty trace."""
         self.tracer.dump(path)
 
+    def _phase(self, name: str, **args):
+        """One step phase on the profiler's clock, mirrored to libra-trace
+        when armed (:mod:`repro.obs.phase`)."""
+        return phase(self.tracer, self._now, name, **args)
+
     def _now(self) -> float:
         if self._start_time is None:
             self._start_time = self.cfg.clock()
@@ -467,10 +485,13 @@ class ServingEngine:
     def step(self) -> None:
         now = self._now()
         if self.swapper.due(now):
-            self._observe_batch_size(now)
-            self.swapper.tick(now)
-            self._execute_swaps(self.manager.drain_ops())
-        self._admit_waiting()
+            with self._phase(PH_SWAPPER):
+                self._observe_batch_size(now)
+                self.swapper.tick(now)
+                self._execute_swaps(self.manager.drain_ops())
+        if self.waiting:
+            with self._phase(PH_ADMIT):
+                self._admit_waiting()
         t0 = time.perf_counter()
         if self.cfg.schedule_mode == "mixed":
             tokens, planned, budget = self._mixed_step()
@@ -523,11 +544,12 @@ class ServingEngine:
         # batch prefills in flight
         fast = frozenset(r.slot for r in prefill_rows
                          if r.priority > PRIORITY_BATCH)
-        plan = plan_step(
-            [r.slot for r in decode_rows],
-            [(r.slot, len(r.prompt) - r.prefill_pos) for r in prefill_rows],
-            budget=budget, chunk_ceiling=self._prefill_chunk,
-            fast_slots=fast)
+        with self._phase(PH_PLAN):
+            plan = plan_step(
+                [r.slot for r in decode_rows],
+                [(r.slot, len(r.prompt) - r.prefill_pos) for r in prefill_rows],
+                budget=budget, chunk_ceiling=self._prefill_chunk,
+                fast_slots=fast)
         if not plan.prefill_chunks:
             # pure-decode step: reuse the dedicated S=1 jit instead of
             # padding every decode token to the smallest prefill bucket
@@ -595,31 +617,35 @@ class ServingEngine:
         Returns the rows that completed prefill and entered DECODE."""
         t_dispatch = self._now()
         bucket = self.prefill.bucket_for(max(chunks.values()))
-        tokens, true_lens, row_mask = assemble_batch(
-            self.cfg.max_batch_slots, bucket,
-            {s: by_slot[s].prompt[by_slot[s].prefill_pos:
-                                  by_slot[s].prefill_pos + c]
-             for s, c in chunks.items()},
-            {r.slot: r.generated[-1] for r in decode_rows})
-        chunk_mask = np.zeros((self.cfg.max_batch_slots,), bool)
-        for s in chunks:
-            chunk_mask[s] = True
-        ids = self._adapter_ids()
+        with self._phase(PH_PLAN):
+            tokens, true_lens, row_mask = assemble_batch(
+                self.cfg.max_batch_slots, bucket,
+                {s: by_slot[s].prompt[by_slot[s].prefill_pos:
+                                      by_slot[s].prefill_pos + c]
+                 for s, c in chunks.items()},
+                {r.slot: r.generated[-1] for r in decode_rows})
+            chunk_mask = np.zeros((self.cfg.max_batch_slots,), bool)
+            for s in chunks:
+                chunk_mask[s] = True
+            ids = self._adapter_ids()
         # tokens/true_lens/row_mask stay host-side np arrays: BatchPrefill
         # does its stats math on them before dispatch, and wrapping them in
         # jnp.asarray here forced a device round trip per step (jit commits
         # them to device at dispatch either way)
-        last_logits, new_cache = self.prefill(
-            self.params, self.adapters.slots, self.cache,
-            tokens, jnp.asarray(self.cache["len"]),
-            true_lens, row_mask, ids,
-            stat_mask=chunk_mask,
-        )
+        with self._phase(PH_DISPATCH, kind="mixed", bucket=bucket,
+                         rows=len(chunks) + len(decode_rows)):
+            last_logits, new_cache = self.prefill(
+                self.params, self.adapters.slots, self.cache,
+                tokens, jnp.asarray(self.cache["len"]),
+                true_lens, row_mask, ids,
+                stat_mask=chunk_mask,
+            )
         self.cache = new_cache
         # sampled tokens must reach Python for generation/finish
         # bookkeeping: ONE batched transfer per step is the right shape
-        # libra: ignore[host-sync]
-        toks = np.asarray(jnp.argmax(last_logits, axis=-1))
+        with self._phase(PH_READBACK):
+            # libra: ignore[host-sync]
+            toks = np.asarray(jnp.argmax(last_logits, axis=-1))
         t_done = self._now()  # post-transfer: the dispatch actually finished
         for r in decode_rows:
             if self.tracer.enabled:
@@ -907,9 +933,10 @@ class ServingEngine:
             prefix_len = m.matched_tokens
             # load matched prefix KV from pool blocks into the dense cache
             if prefix_len > 0:
-                block_ids = [b for n in m.kv_nodes for b in n.hbm_blocks]
-                k, v = self.kv_pool.gather(block_ids)
-                self._write_dense(slot, 0, k, v)
+                with self._phase(PH_GATHER):
+                    block_ids = [b for n in m.kv_nodes for b in n.hbm_blocks]
+                    k, v = self.kv_pool.gather(block_ids)
+                    self._write_dense(slot, 0, k, v)
         # ensure adapter slot present — unless the request starts inside its
         # shared span: base-model rows need no slot, so a shared-prefix hit
         # lets prefill begin while the adapter is still cold (_adapter_ids
@@ -1052,19 +1079,25 @@ class ServingEngine:
             return 0
         t0 = self._now()
         B = self.cfg.max_batch_slots
-        tokens = np.zeros((B, 1), np.int32)
-        for r in active:
-            tokens[r.slot, 0] = r.generated[-1]
-        ids = self._adapter_ids()
-        logits, new_cache = self._decode_fn(
-            self.params, self.adapters.slots, self.cache,
-            jnp.asarray(tokens), ids,
-        )
-        self._merge_cache(new_cache, rows=[r.slot for r in active])
+        with self._phase(PH_PLAN):
+            tokens = np.zeros((B, 1), np.int32)
+            for r in active:
+                tokens[r.slot, 0] = r.generated[-1]
+            ids = self._adapter_ids()
+        # rows given: the catch-up call for rows whose prefill just ended
+        with self._phase(PH_DISPATCH, kind="decode" if rows is None else "catchup",
+                         bucket=1, rows=len(active)):
+            logits, new_cache = self._decode_fn(
+                self.params, self.adapters.slots, self.cache,
+                jnp.asarray(tokens), ids,
+            )
+        with self._phase(PH_MERGE):
+            self._merge_cache(new_cache, rows=[r.slot for r in active])
         # sampled tokens must reach Python for generation/finish
         # bookkeeping: ONE batched transfer per step is the right shape
-        # libra: ignore[host-sync]
-        toks = np.asarray(jnp.argmax(logits[:, -1, :], axis=-1))
+        with self._phase(PH_READBACK):
+            # libra: ignore[host-sync]
+            toks = np.asarray(jnp.argmax(logits[:, -1, :], axis=-1))
         if self.tracer.enabled:
             t1 = self._now()
             for r in active:
@@ -1081,6 +1114,10 @@ class ServingEngine:
             done = True
         if not done:
             return
+        with self._phase(PH_FINISH):
+            self._finish(req)
+
+    def _finish(self, req: Request) -> None:
         now = self._now()
         req.finish_time = now
         req.phase = Phase.FINISHED
@@ -1128,36 +1165,26 @@ class ServingEngine:
         bs = self.cfg.block_size
         suffix_total = len(full) - prefix
         cache_tokens = (suffix_total // bs) * bs
-        if cache_tokens > 0 and self.manager.config.reuse_history_kv:
-            blocks = self.manager.running_blocks(req.request_id)
-            keep = blocks[: cache_tokens // bs]
-            k, v = self._read_dense(req.slot, prefix, prefix + cache_tokens)
-            self.kv_pool.scatter(keep, k, v)
-        self.manager.commit(req.request_id, req.lookup, full, now)
+        with self._phase(PH_SCATTER):
+            if cache_tokens > 0 and self.manager.config.reuse_history_kv:
+                blocks = self.manager.running_blocks(req.request_id)
+                keep = blocks[: cache_tokens // bs]
+                k, v = self._read_dense(req.slot, prefix, prefix + cache_tokens)
+                self.kv_pool.scatter(keep, k, v)
+            self.manager.commit(req.request_id, req.lookup, full, now)
         self.manager.unpin(req.pinned)
 
     # ------------------------------------------------------------ swaps
     def _execute_swaps(self, ops, req: Optional[Request] = None) -> None:
+        """Run the manager's swap ops on the data plane. A swap-in or adapter
+        load returns once its copy is enqueued, so its time (``cache.swap``
+        phase, ``swap.*`` span, TTFT charge) is the enqueue plus any compile;
+        a swap-out waits for its device-to-host copy."""
         for op in ops:
             t0 = self._now()
-            if op.node_kind is NodeKind.LORA:
-                if op.kind is SwapKind.SWAP_IN:
-                    self.adapters.load(op.lora_id)
-                elif op.kind in (SwapKind.SWAP_OUT, SwapKind.DROP):
-                    self.adapters.unload(op.lora_id)
-            elif op.node_kind is NodeKind.STATE:
-                # whole-snapshot moves through the two-tier StateCache
-                if op.kind is SwapKind.SWAP_IN:
-                    self.state_cache.swap_in(op.src_blocks, op.dst_blocks)
-                elif op.kind is SwapKind.SWAP_OUT:
-                    self.state_cache.swap_out(op.src_blocks, op.dst_blocks)
-                # DROP: nothing physical to do
-            else:
-                if op.kind is SwapKind.SWAP_IN:
-                    self.kv_pool.swap_in(op.src_blocks, op.dst_blocks)
-                elif op.kind is SwapKind.SWAP_OUT:
-                    self.kv_pool.swap_out(op.src_blocks, op.dst_blocks)
-                # DROP: nothing physical to do
+            with self._phase(PH_SWAP, kind=op.kind.value,
+                             node=op.node_kind.name, bytes=op.nbytes):
+                self._swap(op)
             t1 = self._now()
             if req is not None:
                 # cold-start accounting (paper Fig. 12): swap-ins only
@@ -1177,6 +1204,27 @@ class ServingEngine:
                     TRACK_SWAPPER, "swap." + op.kind.value, t0, t1,
                     kind=op.node_kind.name, lora=op.lora_id,
                     bytes=op.nbytes, node=op.node_id)
+
+    def _swap(self, op) -> None:
+        """The data-plane move of one swap op."""
+        if op.node_kind is NodeKind.LORA:
+            if op.kind is SwapKind.SWAP_IN:
+                self.adapters.load(op.lora_id)
+            elif op.kind in (SwapKind.SWAP_OUT, SwapKind.DROP):
+                self.adapters.unload(op.lora_id)
+        elif op.node_kind is NodeKind.STATE:
+            # whole-snapshot moves through the two-tier StateCache
+            if op.kind is SwapKind.SWAP_IN:
+                self.state_cache.swap_in(op.src_blocks, op.dst_blocks)
+            elif op.kind is SwapKind.SWAP_OUT:
+                self.state_cache.swap_out(op.src_blocks, op.dst_blocks)
+            # DROP: nothing physical to do
+        else:
+            if op.kind is SwapKind.SWAP_IN:
+                self.kv_pool.swap_in(op.src_blocks, op.dst_blocks)
+            elif op.kind is SwapKind.SWAP_OUT:
+                self.kv_pool.swap_out(op.src_blocks, op.dst_blocks)
+            # DROP: nothing physical to do
 
     # ------------------------------------------------------------- helpers
     def _adapter_ids(self, base_rows: tuple[int, ...] = ()) -> jax.Array:

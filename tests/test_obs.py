@@ -54,12 +54,10 @@ def test_span_duration_and_counters():
     tr.counter("queue_depth", 3.0, waiting=2.0)
     tr.count("cache.evict")
     tr.count("cache.evict", 2)
-    tr.gauge("hbm", 0.7)
     evs = list(tr.events)
     assert evs[0].dur == 0.5 and evs[0].args == {"rid": "r1"}
     assert evs[1].dur == 0.0
     assert tr.counts["cache.evict"] == 3
-    assert tr.gauges["hbm"] == 0.7
 
 
 def test_null_tracer_is_inert():
@@ -69,10 +67,8 @@ def test_null_tracer_is_inert():
     NULL_TRACER.instant(TRACK_ENGINE, "x", 0.0)
     NULL_TRACER.audit("cache.evict", 0.0, node_id=1)
     NULL_TRACER.count("x")
-    NULL_TRACER.gauge("x", 1.0)
     assert len(NULL_TRACER.events) == 0
     assert NULL_TRACER.counts == {}
-    assert NULL_TRACER.gauges == {}
 
 
 def test_export_chrome_is_valid_trace(tmp_path):
