@@ -185,7 +185,7 @@ def kv_pool_spec(model_cfg, block_size: int) -> KVPoolSpec:
     """The engine's per-token pool layout for ``model_cfg`` (float32)."""
     if model_cfg.mla is not None:
         # the pool stores the compressed latent + rope key as ONE
-        # pseudo-head per token (what _read_dense/_write_dense move),
+        # pseudo-head per token (what the pool's copy programs move),
         # not the expanded num_kv_heads × head_dim layout
         m = model_cfg.mla
         kv_heads, head_dim = 1, m.kv_lora_rank + m.qk_rope_head_dim
@@ -287,6 +287,10 @@ class ServingEngine:
         )
         # dense running cache: fixed decode slots
         self.cache = self.model.init_cache(B, T)
+        if self.kv_pool is not None:
+            # every piece size of the pool's copies compiles here, so no
+            # first-seen block count compiles while requests are served
+            self._set_dense(self.kv_pool.warm(self._dense()))
         self._slot_req: list[Optional[Request]] = [None] * B
         self._free_slots = deque(range(B))
         self.waiting: deque[Request] = deque()
@@ -355,6 +359,8 @@ class ServingEngine:
             "prefill": self.prefill.compile_count,
             "decode": jit_cache_size(self._decode_fn),
         }
+        if self.kv_pool is not None:
+            counts["kv_copy"] = self.kv_pool.compile_count()
         if self.state_cache is not None:
             counts["state"] = (
                 jit_cache_size(self._state_seed_fn)
@@ -885,13 +891,11 @@ class ServingEngine:
             else:
                 computed = victim.prompt[: victim.prefill_pos]
             bs = self.cfg.block_size
-            cache_tokens = ((len(computed) - prefix_len) // bs) * bs
-            if cache_tokens > 0 and self.manager.config.reuse_history_kv:
-                blocks = self.manager.running_blocks(victim.request_id)
-                keep = blocks[: cache_tokens // bs]
-                k, v = self._read_dense(
-                    slot, prefix_len, prefix_len + cache_tokens)
-                self.kv_pool.scatter(keep, k, v)
+            n = (len(computed) - prefix_len) // bs
+            if n > 0 and self.manager.config.reuse_history_kv:
+                keep = self.manager.running_blocks(victim.request_id)[:n]
+                with self._phase(PH_SCATTER, blocks=n, pieces=n.bit_count()):
+                    self.kv_pool.scatter(keep, self._dense(), slot, prefix_len)
             self.manager.preempt_running(
                 victim.request_id, victim.lookup, computed, now)
             self.manager.unpin(victim.pinned)
@@ -935,8 +939,8 @@ class ServingEngine:
             if prefix_len > 0:
                 with self._phase(PH_GATHER):
                     block_ids = [b for n in m.kv_nodes for b in n.hbm_blocks]
-                    k, v = self.kv_pool.gather(block_ids)
-                    self._write_dense(slot, 0, k, v)
+                    self._set_dense(self.kv_pool.gather(
+                        block_ids, self._dense(), slot, 0))
         # ensure adapter slot present — unless the request starts inside its
         # shared span: base-model rows need no slot, so a shared-prefix hit
         # lets prefill begin while the adapter is still cold (_adapter_ids
@@ -1163,14 +1167,12 @@ class ServingEngine:
         prefix = m.matched_tokens
         full = req.full_tokens
         bs = self.cfg.block_size
-        suffix_total = len(full) - prefix
-        cache_tokens = (suffix_total // bs) * bs
-        with self._phase(PH_SCATTER):
-            if cache_tokens > 0 and self.manager.config.reuse_history_kv:
-                blocks = self.manager.running_blocks(req.request_id)
-                keep = blocks[: cache_tokens // bs]
-                k, v = self._read_dense(req.slot, prefix, prefix + cache_tokens)
-                self.kv_pool.scatter(keep, k, v)
+        n = ((len(full) - prefix) // bs
+             if self.manager.config.reuse_history_kv else 0)
+        with self._phase(PH_SCATTER, blocks=n, pieces=n.bit_count()):
+            if n > 0:
+                keep = self.manager.running_blocks(req.request_id)[:n]
+                self.kv_pool.scatter(keep, self._dense(), req.slot, prefix)
             self.manager.commit(req.request_id, req.lookup, full, now)
         self.manager.unpin(req.pinned)
 
@@ -1178,12 +1180,16 @@ class ServingEngine:
     def _execute_swaps(self, ops, req: Optional[Request] = None) -> None:
         """Run the manager's swap ops on the data plane. A swap-in or adapter
         load returns once its copy is enqueued, so its time (``cache.swap``
-        phase, ``swap.*`` span, TTFT charge) is the enqueue plus any compile;
-        a swap-out waits for its device-to-host copy."""
+        phase, ``swap.*`` span, TTFT charge) is the enqueue; a swap-out
+        waits for its device-to-host copy."""
         for op in ops:
             t0 = self._now()
+            n = len(op.src_blocks)
+            # pieces: program calls of a KV move (kvcache/paged.py)
+            kv_move = op.node_kind is NodeKind.KV and op.is_transfer
             with self._phase(PH_SWAP, kind=op.kind.value,
-                             node=op.node_kind.name, bytes=op.nbytes):
+                             node=op.node_kind.name, bytes=op.nbytes,
+                             blocks=n, pieces=n.bit_count() if kv_move else 0):
                 self._swap(op)
             t1 = self._now()
             if req is not None:
@@ -1296,39 +1302,12 @@ class ServingEngine:
             merged[key] = jnp.where(m, new, self.cache[key])
         self.cache = merged
 
-    def _write_dense(self, slot: int, start: int, k, v) -> None:
-        """Place gathered prefix KV (L, T, H, D) into the dense cache rows."""
-        T = k.shape[1]
-        if self.model_cfg.mla is not None:
-            m = self.model_cfg.mla
-            latent = k[..., 0, : m.kv_lora_rank]
-            krope = k[..., 0, m.kv_lora_rank : m.kv_lora_rank + m.qk_rope_head_dim]
-            self.cache["latent"] = jax.lax.dynamic_update_slice(
-                self.cache["latent"], latent[:, None].astype(self.cache["latent"].dtype),
-                (0, slot, start, 0))
-            self.cache["krope"] = jax.lax.dynamic_update_slice(
-                self.cache["krope"], krope[:, None].astype(self.cache["krope"].dtype),
-                (0, slot, start, 0))
-            return
-        self.cache["k"] = jax.lax.dynamic_update_slice(
-            self.cache["k"], k[:, None].astype(self.cache["k"].dtype),
-            (0, slot, start, 0, 0))
-        self.cache["v"] = jax.lax.dynamic_update_slice(
-            self.cache["v"], v[:, None].astype(self.cache["v"].dtype),
-            (0, slot, start, 0, 0))
+    def _dense(self) -> tuple:
+        """The dense cache leaves the KV pool copies to and from."""
+        return tuple(self.cache[k] for k in self.kv_spec.dense_keys)
 
-    def _read_dense(self, slot: int, start: int, end: int):
-        """Read dense cache rows back as (L, T, H, D) for pool scatter."""
-        if self.model_cfg.mla is not None:
-            # pool row == concat(latent, krope): kv_spec.head_dim is
-            # constructed as kv_lora_rank + qk_rope_head_dim
-            latent = self.cache["latent"][:, slot, start:end]
-            krope = self.cache["krope"][:, slot, start:end]
-            k = jnp.concatenate([latent, krope], axis=-1)
-            return k[:, :, None, :], None
-        k = self.cache["k"][:, slot, start:end]
-        v = self.cache["v"][:, slot, start:end]
-        return k, v
+    def _set_dense(self, leaves: tuple) -> None:
+        self.cache.update(zip(self.kv_spec.dense_keys, leaves))
 
     def _observe_batch_size(self, now: float) -> None:
         """Report the unified mixed-batch token load to the swapper.

@@ -437,11 +437,14 @@ def test_jit_cache_size_duck_typing():
 def test_compile_count_bounded_on_mixed_trace():
     """A 32-request mixed trace (varied prompt lengths, interleaved decode)
     must compile at most #buckets prefill programs + 1 per fixed-shape
-    phase entry point — per-value recompiles (e.g. a Python scalar sneaking
-    into a jit signature) blow past this bound immediately."""
+    phase entry point + one KV-pool copy program per piece size — per-value
+    recompiles (e.g. a Python scalar sneaking into a jit signature, or a
+    copy whose shape follows the block count) blow past this bound
+    immediately."""
     jax = pytest.importorskip("jax")
     from repro import configs
     from repro.serving import EngineConfig, Request, ServingEngine
+    from repro.kvcache.paged import piece_sizes
 
     cfg = configs.reduced(configs.get("qwen3-0.6b"))
     ecfg = EngineConfig(
@@ -462,7 +465,12 @@ def test_compile_count_bounded_on_mixed_trace():
     counts = eng.compile_counts()
     n_buckets = len(eng.prefill.buckets)
     n_phases = 2  # prefill + decode entry points
+    # scatter and gather: pieces up to a slot's blocks; swap-out's take and
+    # swap-in's put: pieces up to a node both tiers hold
+    to_dense, swaps = eng.kv_pool.copy_limits(ecfg.max_seq_len // 4)
+    n_copies = 2 * len(piece_sizes(to_dense)) + 2 * len(piece_sizes(swaps))
     assert counts["prefill"] <= n_buckets, counts
     assert counts["decode"] <= 1, counts
-    assert sum(counts.values()) <= n_buckets + n_phases, (
+    assert counts["kv_copy"] <= n_copies, counts
+    assert sum(counts.values()) <= n_buckets + n_phases + n_copies, (
         counts, eng.prefill.buckets)
