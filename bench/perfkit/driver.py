@@ -2,11 +2,13 @@
 a timed window, check what it served against the plain reference, and read
 the metrics.
 
-The program is reached only through its API: ``repro.configs.get``,
-``EngineConfig``, ``ServingEngine`` (``register_adapter``, ``submit``,
+The program is reached only through its API: ``repro.configs.get`` (in a
+model module's ``program_config``), ``EngineConfig``, ``ServingEngine`` (``register_adapter``, ``submit``,
 ``step``, ``now``, ``compile_counts``, ``manager.stats``, the public
 ``params`` and ``adapters`` it serves from) and ``Request``. The weights are
-the benchmark's own (weights.py), installed in place of the engine's."""
+the benchmark's own, drawn by the configuration's model module
+(``bench/models/``, ``spec.model_module``) and installed in place of the
+engine's."""
 
 from __future__ import annotations
 
@@ -17,13 +19,14 @@ import pathlib
 import shutil
 import sys
 import time
+import types
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import loadgen, reference, spec, tracered, weights, window, work
+from . import loadgen, reference, spec, tracered, weights, window
 
 # the reference compares at most this many served tokens of at most this many
 # requests; served positions are padded to PAD_SERVED per request
@@ -92,45 +95,15 @@ class CompileCounter:
                 {k: tuple(v) for k, v in self.names.items()})
 
 
-# ---------------------------------------------------------------- the model
-def model_config(cfg: dict):
-    """The program's ModelConfig for a configuration file: the program's
-    architecture entry with the file's sizes."""
-    from repro import configs
-    from repro.configs.base import LoRAConfig
-
-    m, sv = cfg, cfg["serving"]
-    lora = sv["lora"]
-    return dataclasses.replace(
-        configs.get(sv["arch"]),
-        num_layers=m["num_hidden_layers"], d_model=m["hidden_size"],
-        num_heads=m["num_attention_heads"],
-        num_kv_heads=m["num_key_value_heads"], head_dim=m["head_dim"],
-        d_ff=m["intermediate_size"], vocab_size=m["vocab_size"],
-        rope_theta=float(m["rope_theta"]), norm_eps=float(m["rms_norm_eps"]),
-        tie_embeddings=bool(m["tie_word_embeddings"]), qk_norm=True,
-        activation="silu",
-        lora=LoRAConfig(rank=lora["rank"], alpha=float(lora["alpha"]),
-                        targets=tuple(lora["targets"]),
-                        max_adapters=lora["max_adapters"]))
-
-
-def kv_bytes_per_token(s: work.Shape) -> int:
-    return s.layers * 2 * s.kv_heads * s.head_dim * s.itemsize
-
-
-def adapter_bytes(s: work.Shape) -> int:
-    return s.layers * s.itemsize * sum(
-        din * s.lora_rank + s.lora_rank * dout
-        for t, (din, dout) in s.proj_dims().items() if t in s.lora_targets)
-
-
-def engine_config(cfg: dict, s: work.Shape):
+# ---------------------------------------------------------------- the engine
+def engine_config(model: types.ModuleType, cfg: dict, s):
+    """The configuration's engine settings, its pool tiers sized in tokens
+    and adapters by the model's per-token and per-adapter bytes."""
     from repro.serving import EngineConfig
 
     e = dict(cfg["serving"]["engine"])
     pool = e.pop("pool")
-    kv, ad = kv_bytes_per_token(s), adapter_bytes(s)
+    kv, ad = model.kv_bytes_per_token(s), model.adapter_bytes(s)
     return EngineConfig(
         hbm_bytes=pool["hbm_tokens"] * kv + pool["hbm_adapters"] * ad,
         host_bytes=pool["host_tokens"] * kv + pool["host_adapters"] * ad,
@@ -154,7 +127,8 @@ class RunState:
     cell: dict
     cfg: dict
     mix: dict
-    shape: work.Shape
+    model: types.ModuleType  # bench/models/<model_type>.py
+    shape: object  # model.Shape
     device_kind: str
     traced: bool
     obs: dict[str, window.Obs]
@@ -341,7 +315,7 @@ def pick_sample(h: Harness, w0: float, w1: float, seed: int) -> list[str]:
     return pick
 
 
-def _logits(params, adapters_dev, c: reference.Consts, seq, pad_len: int,
+def _logits(model: types.ModuleType, params, adapters_dev, c, seq, pad_len: int,
             precision: str) -> np.ndarray:
     """The reference's logits at each served position of ``seq``."""
     ai, prompt, served, shared = seq
@@ -352,29 +326,29 @@ def _logits(params, adapters_dev, c: reference.Consts, seq, pad_len: int,
     n = len(served)
     at[:n] = np.arange(len(prompt) - 1, len(prompt) - 1 + n)
     ad = {t: (a[ai], b[ai]) for t, (a, b) in adapters_dev.items()}
-    return np.asarray(reference.logits_at(
+    return np.asarray(model.logits_at(
         params, ad, jnp.asarray(toks), jnp.asarray(at), jnp.int32(shared),
         c=c, precision=precision))[:n]
 
 
-def compare(params, adapters_dev, c: reference.Consts, seqs,
+def compare(model: types.ModuleType, params, adapters_dev, c, seqs,
             pad_len: int) -> list[np.ndarray]:
     """Per sampled request, the gap of each served token below the oracle's
-    best. ``seqs``: (adapter index, prompt, served, leading tokens served
-    without the adapter)."""
-    return [reference.served_gaps(_logits(params, adapters_dev, c, q, pad_len,
-                                           "highest"), np.asarray(q[2]))
+    best (``model.logits_at`` with ``c = model.Consts``). ``seqs``: (adapter
+    index, prompt, served, leading tokens served without the adapter)."""
+    return [reference.served_gaps(_logits(model, params, adapters_dev, c, q,
+                                          pad_len, "highest"), np.asarray(q[2]))
             for q in seqs]
 
 
-def control_seqs(params, adapters_dev, c: reference.Consts, seqs, pad_len: int,
-                 precision: str) -> list[tuple]:
+def control_seqs(model: types.ModuleType, params, adapters_dev, c, seqs,
+                 pad_len: int, precision: str) -> list[tuple]:
     """The control in the program's place: ``seqs`` with each served token
     replaced by the token the lower-precision reference puts first at the
     same position, to be judged exactly as the program is."""
     return [(ai, prompt, tuple(int(t) for t in _logits(
-                params, adapters_dev, c, (ai, prompt, served, shared), pad_len,
-                precision).argmax(-1)), shared)
+                model, params, adapters_dev, c, (ai, prompt, served, shared),
+                pad_len, precision).argmax(-1)), shared)
             for ai, prompt, served, shared in seqs]
 
 
@@ -399,23 +373,24 @@ def stats_dict(engine) -> dict:
     return dataclasses.asdict(engine.manager.stats)
 
 
-def build(cfg: dict, seed: int, n_adapters: int):
-    """The engine with the benchmark's weights and ``n_adapters`` adapters
-    registered; returns (engine, shape, params, adapters on device)."""
+def build(model: types.ModuleType, cfg: dict, seed: int, n_adapters: int):
+    """The engine with the model module's weights and ``n_adapters``
+    adapters registered; returns (engine, shape, params, adapters on
+    device)."""
     from repro.serving import ServingEngine
 
-    s = work.Shape.from_config(cfg)
-    engine = ServingEngine(model_config(cfg), engine_config(cfg, s),
+    s = model.Shape.from_config(cfg)
+    engine = ServingEngine(model.program_config(cfg), engine_config(model, cfg, s),
                            key=jax.random.PRNGKey(0))
     key = weights.seed_key(seed)
     kp, ka = jax.random.split(key)
-    weights.check_layout(jax.eval_shape(weights.make_params, kp, s),
+    weights.check_layout(jax.eval_shape(model.make_params, kp, s),
                          engine.params)
     engine.params = None
     gc.collect()
-    params = weights.make_params(kp, s)
+    params = model.make_params(kp, s)
     engine.params = params
-    adapters = weights.make_adapters(ka, s, n_adapters)
+    adapters = model.make_adapters(ka, s, n_adapters)
     host = jax.device_get(adapters)
     for i in range(n_adapters):
         name = f"lora-{i}"
@@ -464,8 +439,9 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool,
     counter = CompileCounter()
     cfg = cfg_override or spec.find_config(bench, cell["config"], root)
     mix = mix_override or spec.load_mix(cell["traffic"], root / "bench")
+    model = spec.model_module(cfg, root / "bench")
     n_adapters = int(mix["adapters_registered"])
-    engine, shape, params, adapters = build(cfg, seed, n_adapters)
+    engine, shape, params, adapters = build(model, cfg, seed, n_adapters)
     t_built = time.perf_counter()
     warm_shapes(engine, engine.prefill.buckets, cfg["serving"]["engine"]["block_size"])
     t_warm = time.perf_counter()
@@ -534,7 +510,7 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool,
                      tuple(r.output_tokens), int(r.shared_prefix_len)))
     # a traced run's per-layer metrics cover the traced span only: stopping
     # the profiler stalls the loop for seconds after it
-    state = RunState(cell=cell, cfg=cfg, mix=mix, shape=shape,
+    state = RunState(cell=cell, cfg=cfg, mix=mix, model=model, shape=shape,
                      device_kind=dev.device_kind, traced=trace, obs=h.obs,
                      w0=w0, w1=wt if trace else w1, stats0=stats0,
                      stats1=stats_t if trace else stats1,
@@ -552,10 +528,10 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool,
     h_obs, calls = h.obs, h.calls
     del h, engine, traffic
     gc.collect()
-    c = reference.Consts.from_config(cfg)
+    c = model.Consts.from_config(cfg)
     pad = int(cfg["serving"]["engine"]["max_seq_len"])
     t_ref = time.perf_counter()
-    gaps = compare(params, adapters, c, seqs, pad)
+    gaps = compare(model, params, adapters, c, seqs, pad)
     log(f"reference over {len(seqs)} requests: {time.perf_counter() - t_ref:.3f} s")
     diag = {"backlog_at_open": backlog(h_obs, w0), "backlog_at_close": backlog(h_obs, w1),
             "finished_in_window": sum(1 for o in h_obs.values()
@@ -569,9 +545,9 @@ def run_cell(cell_name: str, seed: int, seconds: float, trace: bool,
                            for k, g in zip(sample, gaps)]
     correct, checks = judge(gaps, seqs, len(failed), cfg["limits"], shape.vocab)
     if control:
-        cseqs = control_seqs(params, adapters, c, seqs, pad,
+        cseqs = control_seqs(model, params, adapters, c, seqs, pad,
                              cfg["limits"]["control_precision"])
-        cg = compare(params, adapters, c, cseqs, pad)
+        cg = compare(model, params, adapters, c, cseqs, pad)
         for row, g in zip(diag["per_request"], cg):
             row.append(float(g.max()))
         ccorrect, cchecks = judge(cg, cseqs, len(failed), cfg["limits"], shape.vocab)

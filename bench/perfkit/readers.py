@@ -6,7 +6,8 @@ from __future__ import annotations
 
 from typing import Optional
 
-from . import peaks, window, work
+from . import peaks, spec, window
+
 
 def pct(x: Optional[float]) -> Optional[float]:
     return None if x is None else 100.0 * x
@@ -27,7 +28,7 @@ def share(num: float, den: float) -> Optional[float]:
 def step_flops(run) -> int:
     """Model FLOPs of every token the window's dispatches computed."""
     s = run.shape
-    return sum(work.span_flops(s, start, n, emits)
+    return sum(run.model.span_flops(s, start, n, emits)
                for c in run.calls for start, n, emits, _ in c.rows)
 
 
@@ -46,13 +47,18 @@ def idle_share(run) -> Optional[float]:
 
 def kernel_roofline(run, kernel: str, least_s) -> Optional[float]:
     """Least time of the needed work (``least_s(run)``) over the kernel's
-    summed device time; None where the trace holds none of it or the device
-    has no published peaks."""
+    summed device time; None where the trace holds none of it (the model's
+    path runs no such kernel) or the device has no published peaks. A
+    kernel that ran on a model whose module counts no ``<kernel>_need`` is
+    a fault of the module, and raises."""
     if run.trace is None or run.device_kind not in peaks.PEAKS:
         return None
     t = run.trace.kernel_s(kernel)
     if t <= 0:
         return None
+    if not hasattr(run.model, f"{kernel}_need"):
+        raise spec.SpecError(f"{kernel} ran for {t} s, but the model module "
+                             f"{run.model.__name__} has no {kernel}_need")
     least = least_s(run)
     return pct(least / t) if least > 0 else None
 
@@ -63,7 +69,7 @@ def ragged_extend_least_s(run) -> float:
     for c in run.calls:
         if c.kind != "mixed":
             continue
-        f, b = work.ragged_extend_need(s, [(st, n) for st, n, _, _ in c.rows])
+        f, b = run.model.ragged_extend_need(s, [(st, n) for st, n, _, _ in c.rows])
         total += s.layers * peaks.least_time(f, b, kind)
     return total
 
@@ -75,6 +81,6 @@ def fused_sgmv_least_s(run) -> float:
         tokens = sum(n for _, n, _, _ in c.rows)
         adapters = len({a for _, _, _, a in c.rows})
         for t in s.lora_targets:
-            f, b = work.fused_sgmv_need(s, t, tokens, adapters)
+            f, b = run.model.fused_sgmv_need(s, t, tokens, adapters)
             total += s.layers * peaks.least_time(f, b, kind)
     return total

@@ -1,14 +1,17 @@
 """Find a cell's pieces by name: the cell in ``BENCHMARK.json``, its
-configuration file, its traffic mix and the per-layer metric readers.
+configuration file, the model module of the configuration's
+``model_type``, its traffic mix and the per-layer metric readers.
 
-Adding a configuration, a mix or a metric means adding a file and an entry;
-nothing here names one."""
+Adding a configuration, a model, a mix or a metric means adding a file and
+an entry; nothing here names one."""
 
 from __future__ import annotations
 
 import importlib.util
 import json
 import pathlib
+import sys
+import types
 from typing import Callable, Optional
 
 BENCH_DIR = pathlib.Path(__file__).resolve().parents[1]
@@ -68,14 +71,34 @@ def metrics_for(bench: dict, cell: str, section: str) -> list[dict]:
             if "workloads" not in m or cell in m["workloads"]]
 
 
+def _load(kind: str, name: str, path: pathlib.Path) -> types.ModuleType:
+    """Load ``path`` afresh as a module, registered under its name while
+    it runs (a dataclass looks its module up there)."""
+    spec = importlib.util.spec_from_file_location(
+        f"perfkit_{kind}_" + name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def model_module(cfg: dict, bench_dir: pathlib.Path = BENCH_DIR) -> types.ModuleType:
+    """The module ``bench/models/<model_type>.py`` of a configuration's
+    published ``model_type``: its shape, weights, reference and work counts
+    (the contract is in ``bench/models/__init__.py``)."""
+    name = cfg.get("model_type")
+    if not name:
+        raise SpecError(f"configuration {cfg.get('name')!r} names no model_type")
+    path = bench_dir / "models" / f"{name}.py"
+    if not path.is_file():
+        raise SpecError(f"no model module for model_type {name!r} at {path}")
+    return _load("model", name, path)
+
+
 def metric_reader(name: str,
                   bench_dir: pathlib.Path = BENCH_DIR) -> Callable[..., Optional[float]]:
     """The ``read(run)`` function of ``bench/metrics/<name>.py``."""
     path = bench_dir / "metrics" / f"{name}.py"
     if not path.is_file():
         raise SpecError(f"no reader for per-layer metric {name!r} at {path}")
-    spec = importlib.util.spec_from_file_location(
-        "perfkit_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load("metric", name, path).read

@@ -47,11 +47,13 @@ def calls_from_spans(events, requests) -> list[tuple[str, list]]:
 def test_harness_dispatch_record_matches_the_engines(monkeypatch, mix_name):
     real = driver.engine_config
     monkeypatch.setattr(driver, "engine_config",
-                        lambda cfg, s: dataclasses.replace(real(cfg, s), trace=True))
+                        lambda model, cfg, s: dataclasses.replace(real(model, cfg, s),
+                                                                  trace=True))
     bench = spec.load_benchmark()
     cfg = tiny(spec.find_config(bench, "qwen3-0.6b"))
     mix = tiny_mix(spec.load_mix(mix_name))
-    engine, shape, _, _ = driver.build(cfg, SEED, int(mix["adapters_registered"]))
+    engine, shape, _, _ = driver.build(spec.model_module(cfg), cfg, SEED,
+                                       int(mix["adapters_registered"]))
     assert engine.tracer.enabled
     block = cfg["serving"]["engine"]["block_size"]
     driver.warm_shapes(engine, engine.prefill.buckets, block)

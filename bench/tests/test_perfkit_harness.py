@@ -140,12 +140,12 @@ def test_comparison_sides_with_a_cold_engine():
     more than zero when they do not. (A turn whose history ends on a block
     boundary reuses a block holding the KV of a token the engine never ran;
     PERF.md, Open questions.)"""
-    from perfkit import reference
     from repro.serving import Request
 
     bench = spec.load_benchmark()
     cfg = tiny(spec.find_config(bench, "qwen3-0.6b"))
-    c = reference.Consts.from_config(cfg)
+    qwen3 = spec.model_module(cfg)
+    c = qwen3.Consts.from_config(cfg)
     rng = np.random.default_rng(3)
 
     def serve(engine, prompt, new, rid):
@@ -155,17 +155,18 @@ def test_comparison_sides_with_a_cold_engine():
             engine.step()
         return tuple(r.output_tokens)
 
-    warm, _, params, adapters = driver.build(cfg, 5, 2)
+    warm, _, params, adapters = driver.build(qwen3, cfg, 5, 2)
     cases = []
     for plen in (24, 25):  # with 8 new tokens: 32 (a block boundary) and 33
         first = [int(x) for x in rng.integers(0, 512, plen)]
         reply = serve(warm, first, 8, f"a{plen}")
         second = list(first) + list(reply) + [int(x) for x in rng.integers(0, 512, 20)]
         cases.append((second, serve(warm, second, 12, f"b{plen}")))
-    cold, _, _, _ = driver.build(cfg, 5, 2)
+    cold, _, _, _ = driver.build(qwen3, cfg, 5, 2)
     for i, (prompt, served) in enumerate(cases):
         fresh = serve(cold, prompt, 12, f"c{i}")
-        gap = driver.compare(params, adapters, c, [(1, tuple(prompt), served, 0)], 256)[0]
+        gap = driver.compare(qwen3, params, adapters, c, [(1, tuple(prompt), served, 0)],
+                             256)[0]
         assert (gap.max() == 0.0) == (served == fresh), (i, gap.max())
 
 
